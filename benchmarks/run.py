@@ -57,6 +57,8 @@ def main() -> None:
     for path in common.write_bench_artifacts():
         print(f"artifact/{path},0,written")
     print(f"summary/sections_failed,0,{failures}")
+    if failures:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

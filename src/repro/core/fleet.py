@@ -42,6 +42,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from .datasche import AlgoSpec, DS, SWITCHED, SWITCHED_NOAID, SlotRecord, step
 from .job import JobLike, SliceJob, as_jobs
@@ -91,21 +92,39 @@ def trim_state(state: SchedulerState, shape: ShapeConfig) -> SchedulerState:
     )
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2),
+                   static_argnames=("mesh", "axis_name"))
 def _fleet_scan(shape: ShapeConfig, spec: AlgoSpec, n_slots: int,
-                params: SliceParams, state: SchedulerState
+                params: SliceParams, state: SchedulerState, mesh=None,
+                axis_name: str = "data"
                 ) -> tuple[SchedulerState, SlotRecord]:
+    """The slot program: ``vmap(step)`` over the K slices inside one scan.
+
+    With ``mesh``, the program runs per shard of the K axis
+    (``jax.shard_map``): slices are independent, so each device scans its
+    own K / n slices with no communication. This is also what lets the
+    Pallas matchers run sharded: Mosaic kernels are not partitioned
+    automatically."""
     def one_slot(p, s):
         s2, rec, _ = step(shape, spec, s, params=p)
         return s2, rec
 
-    vstep = jax.vmap(one_slot)
+    def scan(params, state):
+        vstep = jax.vmap(one_slot)
 
-    def body(s, _):
-        s2, rec = vstep(params, s)
-        return s2, rec
+        def body(s, _):
+            s2, rec = vstep(params, s)
+            return s2, rec
 
-    return jax.lax.scan(body, state, None, length=n_slots)
+        return jax.lax.scan(body, state, None, length=n_slots)
+
+    if mesh is None:
+        return scan(params, state)
+    k = PartitionSpec(axis_name)
+    # check_vma off: pallas_call out_shapes carry no varying-axes annotation
+    return jax.shard_map(scan, mesh=mesh, in_specs=(k, k),
+                         out_specs=(k, PartitionSpec(None, axis_name)),
+                         check_vma=False)(params, state)
 
 
 def _stacked_slice_count(params: SliceParams) -> int:
@@ -266,9 +285,25 @@ class FleetEngine:
 
         Returns (stacked final state (K, ...), stacked records (T, K)).
         With ``mesh``, the K axis of params/state is sharded over
-        ``mesh[axis_name]`` before the scan (K % axis size must be 0) and XLA
-        partitions every slot across devices.
+        ``mesh[axis_name]`` before the scan (K % axis size must be 0) and
+        each device runs the slot program on its own slices.
         """
+        return _fleet_scan(self.shape, self.spec, n_slots,
+                           *self._placed(state, mesh, axis_name), mesh=mesh,
+                           axis_name=axis_name)
+
+    def lower(self, n_slots: int, state: Optional[SchedulerState] = None,
+              mesh=None, axis_name: str = "data"):
+        """The slot program :meth:`run` executes, lowered but not run:
+        ``.compile()`` gives the executable (a cache hit after a ``run`` of
+        the same shapes) and ``.compile().as_text()`` its HLO."""
+        return _fleet_scan.lower(self.shape, self.spec, n_slots,
+                                 *self._placed(state, mesh, axis_name),
+                                 mesh=mesh, axis_name=axis_name)
+
+    def _placed(self, state, mesh, axis_name):
+        """(params, state) as the slot program takes them: the initial state
+        by default, the K axis sharded over ``mesh[axis_name]`` with a mesh."""
         if state is None:
             state = self.init()
         params = self.params
@@ -276,4 +311,4 @@ class FleetEngine:
             from ..launch.mesh import shard_leading_axis
             params = shard_leading_axis(params, mesh, axis_name)
             state = shard_leading_axis(state, mesh, axis_name)
-        return _fleet_scan(self.shape, self.spec, n_slots, params, state)
+        return params, state

@@ -1,6 +1,7 @@
 """Attention front-end: backend dispatch + memory-efficient chunked jnp path.
 
-``flash_attention`` is what models call. Dispatch:
+``flash_attention`` is what models call. Dispatch, by the platform the
+program is lowered for:
   * TPU        -> the Pallas online-softmax kernel (kernel.py)
   * elsewhere  -> ``attention_chunked``: double-chunked (q and kv) online
                   softmax in pure jnp. O(Cq*Ck) live logits instead of
@@ -17,6 +18,7 @@ tight on both paths. EXPERIMENTS.md corrects for this in MODEL_FLOPS ratios.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -116,13 +118,53 @@ def attention_chunked(q, k, v, q_pos, kv_pos, spec: AttnSpec,
     return jnp.moveaxis(outs, 0, 1).reshape(b, sq, h, hd)
 
 
+def _per_shard(attn, q, k, v, q_pos, kv_pos, kv_valid):
+    """Run ``attn`` once per shard of the model's mesh, if there is one.
+
+    Mosaic kernels are not partitioned automatically, so under a mesh the
+    kernel runs inside ``jax.shard_map``: batch over the data-parallel axes
+    and heads over the tensor axis, each where it divides evenly (else that
+    dim is whole on every shard). Attention is independent per (sample,
+    head), so no collective is needed."""
+    from repro.parallel.sharding import batch_axes, current_mesh, current_style
+    mesh = current_mesh()
+    if kv_valid is None:
+        kv_valid = jnp.ones((q.shape[0], k.shape[1]), bool)
+    if mesh is None or mesh.size == 1:
+        return attn(q, k, v, q_pos, kv_pos, kv_valid)
+    from jax.sharding import PartitionSpec as P
+
+    bax = batch_axes(mesh)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in bax):
+        bax = None
+    hax = None
+    if current_style() != "fsdp":
+        tp = mesh.shape.get("model", 1)
+        if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0:
+            hax = "model"
+    heads, rows = P(bax, None, hax, None), P(bax, None)
+    return jax.shard_map(attn, mesh=mesh,
+                         in_specs=(heads, heads, heads, rows, rows, rows),
+                         out_specs=heads, check_vma=False)(
+        q, k, v, q_pos, kv_pos, kv_valid)
+
+
 def flash_attention(q, k, v, q_pos, kv_pos, spec: AttnSpec, kv_valid=None,
                     scale=None, impl: str = "auto",
                     q_chunk: int = 1024, kv_chunk: int = 1024,
                     interpret: bool = False):
-    """Public attention entry point. impl: auto | pallas | chunked | ref."""
+    """Public attention entry point. impl: auto | pallas | chunked | ref.
+
+    ``auto`` is the Pallas kernel where the program is lowered for a TPU and
+    the chunked path elsewhere (``jax.lax.platform_dependent``: the platform
+    of the compile decides, not the process's default backend)."""
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "chunked"
+        def run(which):
+            return lambda q, k, v: flash_attention(
+                q, k, v, q_pos, kv_pos, spec, kv_valid, scale, which,
+                q_chunk, kv_chunk, interpret)
+        return jax.lax.platform_dependent(q, k, v, tpu=run("pallas"),
+                                          default=run("chunked"))
     if q.shape[1] == 1 and impl == "chunked":
         # decode fast-path: single-pass exact attention. Chunking would
         # dynamic-slice a (possibly sequence-sharded) KV cache and force
@@ -132,9 +174,11 @@ def flash_attention(q, k, v, q_pos, kv_pos, spec: AttnSpec, kv_valid=None,
                              gqa="group")
     if impl == "pallas":
         from . import kernel
-        return kernel.flash_attention_pallas(q, k, v, q_pos, kv_pos, spec,
-                                             kv_valid=kv_valid, scale=scale,
-                                             interpret=interpret)
+        return _per_shard(
+            lambda q, k, v, qp, kp, kval: kernel.flash_attention_pallas(
+                q, k, v, qp, kp, spec, kv_valid=kval, scale=scale,
+                interpret=interpret),
+            q, k, v, q_pos, kv_pos, kv_valid)
     if impl == "chunked":
         return attention_chunked(q, k, v, q_pos, kv_pos, spec, kv_valid,
                                  scale, q_chunk, kv_chunk)

@@ -9,8 +9,12 @@ the core scheduler:
   * ``greedy_pairing``     — Thm.-2 EC pairing (``datasche._train_generic``)
 
 Each routes to the Pallas kernel on TPU and the (bit-identical) jnp reference
-elsewhere; ``impl=`` forces a backend and ``interpret=True`` runs the Pallas
-kernel in interpreter mode (the CPU parity tests).
+elsewhere. The choice follows the platform the program is lowered for
+(``jax.lax.platform_dependent``), not the process's default backend, so a
+program placed on the CPU of a TPU host runs the refs, and a compile for a
+described TPU includes the kernels. ``impl=`` forces a backend and
+``interpret=True`` runs the Pallas kernel in interpreter mode (the CPU parity
+tests).
 
 Batch-compatible: weights with leading batch axes — e.g. a (K, N, M) fleet
 slice axis — are handled by vmapping the 2-D primitive, and calling the 2-D
@@ -39,13 +43,10 @@ from .ref import (greedy_assignment_ref, greedy_collection_ref,
                   greedy_pairing_ref, pairing_value_matrix)
 
 
-def _resolve_impl(impl: str) -> str:
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl not in ("pallas", "ref"):
+def _check_impl(impl: str):
+    if impl not in ("auto", "pallas", "ref"):
         raise ValueError(f"unknown matching impl {impl!r}; "
                          "expected 'auto', 'pallas' or 'ref'")
-    return impl
 
 
 def _entity_masked(w, cu_mask, ec_mask):
@@ -59,17 +60,20 @@ def _entity_masked(w, cu_mask, ec_mask):
 
 
 def _dispatch(operands, impl, interpret, pallas_fn, ref_fn):
-    """Shared dispatch tail of every op (masking already applied): resolve
-    the impl once, vmap away any leading batch axes (the LAST operand is the
+    """Shared dispatch tail of every op (masking already applied): check
+    the impl, vmap away any leading batch axes (the LAST operand is the
     rank-2 reference — (N, M) weights or the (M, M) pair values), then route
     to the Pallas kernel or the jnp ref."""
-    impl = _resolve_impl(impl)
+    _check_impl(impl)
     if operands[-1].ndim > 2:
         return jax.vmap(lambda *ops: _dispatch(
             ops, impl, interpret, pallas_fn, ref_fn))(*operands)
     if impl == "pallas":
         return pallas_fn(*operands, interpret)
-    return ref_fn(*operands)
+    if impl == "ref":
+        return ref_fn(*operands)
+    return jax.lax.platform_dependent(
+        *operands, tpu=lambda *ops: pallas_fn(*ops, interpret), default=ref_fn)
 
 
 def _assignment_pallas(w, interpret):

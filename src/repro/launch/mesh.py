@@ -11,19 +11,29 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """Mesh whose axes are all ``Auto``: the model code places activations
+    and gradients with ``with_sharding_constraint``, which only accepts Auto
+    axes (``jax.make_mesh`` defaults to Explicit ones)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_host_mesh(model_parallel: int = 1):
-    """Small mesh over whatever devices exist (tests / examples)."""
-    n = len(jax.devices())
+def make_host_mesh(model_parallel: int = 1, devices=None):
+    """Small mesh over ``devices`` (default: every device of the process)."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
     mp = max(1, min(model_parallel, n))
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"), devices)
 
 
 def shard_leading_axis(tree, mesh, axis: str = "data"):

@@ -32,6 +32,7 @@ from repro import core
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, reduced as make_reduced
 from repro.data import CocktailSampler, TokenSource
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import build_model
@@ -47,6 +48,46 @@ def build_cocktail(n_cu: int, n_ec: int, seed: int) -> core.CocktailConfig:
                  np.random.default_rng(seed).choice([8000, 14000, 20000, 48000], n_ec))
     return core.CocktailConfig(n_cu=n_cu, n_ec=n_ec, eps=0.1, delta=0.05,
                                f_base=caps, pair_iters=30, seed=seed)
+
+
+def place_training(model, mesh, opt_cfg: AdamWConfig, total_steps: int,
+                   seed: int):
+    """Params and AdamW state from ``seed``, laid out on ``mesh`` by the
+    FSDP x TP rules, and the jitted train step. Call inside
+    ``mesh_context(mesh)``. Returns (params, opt_state, step_fn, params
+    shardings, opt-state shardings)."""
+    params = model.init(jax.random.PRNGKey(seed))
+    opt_state = adamw_init(params)
+    p_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        shard_params_pspecs(params, mesh),
+                        is_leaf=lambda x: isinstance(x, P))
+    o_sh = AdamWState(step=NamedSharding(mesh, P()), m=p_sh, v=p_sh)
+    params = jax.tree.map(jax.device_put, params, p_sh)
+    opt_state = jax.tree.map(jax.device_put, opt_state, o_sh,
+                             is_leaf=lambda x: isinstance(x, jax.Array))
+    step_fn = jax.jit(make_train_step(model, opt_cfg, total_steps=total_steps),
+                      donate_argnums=(0, 1))
+    return params, opt_state, step_fn, p_sh, o_sh
+
+
+def put_batch(batch: dict, mesh) -> dict:
+    """Shard every batch leaf's leading (sample) axis over the DP axes: each
+    data-parallel group is one EC."""
+    bax = batch_axes(mesh)
+    return {k: jax.device_put(v, NamedSharding(
+        mesh, P(bax, *([None] * (v.ndim - 1))))) for k, v in batch.items()}
+
+
+def stub_frontend_inputs(cfg, batch_size: int, key) -> dict:
+    """Random stand-ins for the stubbed modality frontends (frames for
+    enc-dec, image patches for VLM); empty for text-only families."""
+    if cfg.family == "encdec":
+        return {"frames": jax.random.normal(
+            key, (batch_size, cfg.enc_ctx, cfg.d_model))}
+    if cfg.family == "vlm":
+        return {"patches": jax.random.normal(
+            key, (batch_size, cfg.n_img_tokens, cfg.d_model))}
+    return {}
 
 
 def main(argv=None):
@@ -65,13 +106,15 @@ def main(argv=None):
     ap.add_argument("--scheduler", default="ds", choices=sorted(core.ALL_SPECS))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="train on the first N devices (default: all)")
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)
     model = build_model(cfg)
-    mesh = make_host_mesh()
+    mesh = make_host_mesh(devices=jax.devices()[:args.devices or None])
     dp = int(np.prod([dict(zip(mesh.axis_names, mesh.devices.shape))[a]
                       for a in batch_axes(mesh)]))
     n_ec = max(dp, 2)
@@ -96,19 +139,8 @@ def main(argv=None):
     # --- model + optimizer state ---
     opt_cfg = AdamWConfig(lr=args.lr)
     with mesh_context(mesh):
-        params = model.init(jax.random.PRNGKey(args.seed))
-        opt_state = adamw_init(params)
-        p_specs = shard_params_pspecs(params, mesh)
-        ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
-                                    is_leaf=lambda x: isinstance(x, P))
-        p_sh = ns(p_specs)
-        o_sh = AdamWState(step=NamedSharding(mesh, P()), m=p_sh, v=p_sh)
-        params = jax.tree.map(jax.device_put, params, p_sh)
-        opt_state = jax.tree.map(jax.device_put, opt_state, o_sh,
-                                 is_leaf=lambda x: isinstance(x, jax.Array))
-
-        step_fn = jax.jit(make_train_step(model, opt_cfg, total_steps=args.steps),
-                          donate_argnums=(0, 1))
+        params, opt_state, step_fn, p_sh, o_sh = place_training(
+            model, mesh, opt_cfg, args.steps, args.seed)
 
         start = 0
         ckpt = None
@@ -140,19 +172,10 @@ def main(argv=None):
                 "labels": jnp.asarray(host_batch["labels"]),
                 "weights": jnp.asarray(host_batch["weights"]),
             }
-            if cfg.family == "encdec":  # stubbed modality frontends
-                batch["frames"] = jax.random.normal(
-                    jax.random.fold_in(jax.random.PRNGKey(args.seed), it),
-                    (args.batch, cfg.enc_ctx, cfg.d_model))
-            if cfg.family == "vlm":
-                batch["patches"] = jax.random.normal(
-                    jax.random.fold_in(jax.random.PRNGKey(args.seed), it),
-                    (args.batch, cfg.n_img_tokens, cfg.d_model))
-            bax = batch_axes(mesh)
-            def put(x):
-                spec = P(bax, *([None] * (x.ndim - 1)))
-                return jax.device_put(x, NamedSharding(mesh, spec))
-            batch = {k: put(v) for k, v in batch.items()}
+            batch.update(stub_frontend_inputs(
+                cfg, args.batch,
+                jax.random.fold_in(jax.random.PRNGKey(args.seed), it)))
+            batch = put_batch(batch, mesh)
             params, opt_state, metrics = step_fn(params, opt_state, batch)
             losses.append(float(metrics["loss"]))
             if ckpt is not None:
@@ -178,8 +201,9 @@ def main(argv=None):
             "skew_degree": float(core.skew_degree(ck, sched_state.queues.omega)),
         }
         print(json.dumps(summary))
-        return summary
+        return {**summary, "losses": losses}
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,7 +11,12 @@ queues/multipliers and accumulated objective — because
     training allocate exactly zero to them,
   * record scalars are sums to which padded entries contribute exact zeros.
 
-The single-slice padded path is asserted BIT-exact on CPU; the vmapped fleet
+The single-slice padded path is asserted BIT-exact on CPU for everything
+per entity (queues, multipliers, uploads). The scalar sums over the padded
+axes (the per-slot records and the accumulated cost/trained totals) are held
+to SUM_RTOL instead: XLA:CPU vectorises a reduction according to the array's
+length, so the same values plus trailing exact zeros can round differently
+in the last ulp, depending on the host's vector width. The vmapped fleet
 path reuses the tolerances of tests/test_fleet.py (vmap may re-associate
 reductions).
 """
@@ -30,6 +35,9 @@ from repro.core.fleet import slice_records, unstack
 BASE = CocktailConfig(n_cu=8, n_ec=3, eps=0.1, pair_iters=15, seed=7,
                       f_base=(8000.0, 20000.0, 12000.0))
 SLOTS = 10
+# A few float32 ulps (eps = 2**-23): the reassociation bound of a sum whose
+# array length changed; measured at most 2.0e-7 over every policy here.
+SUM_RTOL = 4 * 2.0 ** -23
 
 
 def _padded_run(cfg: CocktailConfig, pad: ShapeConfig, spec, n_slots: int):
@@ -43,7 +51,8 @@ def _assert_records_equal(recs_pad, recs_ref, exact=True):
         a = np.asarray(getattr(recs_ref, field))
         b = np.asarray(getattr(recs_pad, field))
         if exact:
-            np.testing.assert_array_equal(b, a, err_msg=field)
+            np.testing.assert_allclose(b, a, rtol=SUM_RTOL, atol=0,
+                                       err_msg=field)
         else:
             np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-4,
                                        err_msg=field)
@@ -63,8 +72,10 @@ def _assert_trimmed_state_equal(st_pad, st_ref, shape, exact=True):
     assert_eq(np.asarray(tr.uploaded), np.asarray(st_ref.uploaded),
               err_msg="uploaded")
     if exact:
-        assert float(tr.total_cost) == float(st_ref.total_cost)
-        assert float(tr.total_trained) == float(st_ref.total_trained)
+        for name in ("total_cost", "total_trained"):
+            np.testing.assert_allclose(float(getattr(tr, name)),
+                                       float(getattr(st_ref, name)),
+                                       rtol=SUM_RTOL, atol=0, err_msg=name)
     else:
         np.testing.assert_allclose(float(tr.total_cost),
                                    float(st_ref.total_cost), rtol=1e-4)
@@ -196,7 +207,8 @@ def test_ragged_pad_shape_and_mask_layout():
 @pytest.mark.tier2
 def test_padded_equivalence_property():
     """Hypothesis sweep over random true shapes, pad shapes and seeds: the
-    padded DS trace is bit-exact against the unpadded one."""
+    padded DS trace matches the unpadded one (per entity bit-exact, sums
+    within SUM_RTOL)."""
     hypothesis = pytest.importorskip("hypothesis")
     from hypothesis import given, settings, strategies as hst
 
