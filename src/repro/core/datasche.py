@@ -321,51 +321,53 @@ def _compose_from_match(match, x_solo, pairs, pa, m):
     sel = match[pj, pk]  # (P,) 1 if pair matched
     diag = jnp.diagonal(match)  # (M,)
 
+    # float32 throughout: at the default precision the TPU rounds the
+    # operands of these contractions to bfloat16.
+    ein = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
     x = x_solo * diag[None, :]
-    x = x + jnp.einsum("pn,pm->nm", pa.x_j * sel[:, None], onehot_j)
-    x = x + jnp.einsum("pn,pm->nm", pa.x_k * sel[:, None], onehot_k)
-    y = jnp.einsum("pn,pm,pl->nml", pa.y_jk * sel[:, None], onehot_j, onehot_k)
-    y = y + jnp.einsum("pn,pm,pl->nml", pa.y_kj * sel[:, None], onehot_k, onehot_j)
+    x = x + ein("pn,pm->nm", pa.x_j * sel[:, None], onehot_j)
+    x = x + ein("pn,pm->nm", pa.x_k * sel[:, None], onehot_k)
+    y = ein("pn,pm,pl->nml", pa.y_jk * sel[:, None], onehot_j, onehot_k)
+    y = y + ein("pn,pm,pl->nml", pa.y_kj * sel[:, None], onehot_k, onehot_j)
     z = match * (1.0 - jnp.eye(m, dtype=match.dtype))
     return x, y, z
 
 
 def _train_generic(shape, params, net, mults, queues, exact, use_lsa, solo_fn, pair_fn):
-    beta, gamma = training_weights(shape, net, mults, use_lsa, params)
-    budgets = net.f / params.rho
     m = shape.n_ec
-
-    x_solo, val_solo = jax.vmap(solo_fn, in_axes=(1, 1, 0), out_axes=(1, 0))(
-        beta, queues.r, budgets)
-
     pj, pk = _pair_index(m)
     pj_a, pk_a = jnp.asarray(pj), jnp.asarray(pk)
+    with jax.named_scope("allocation"):
+        beta, gamma = training_weights(shape, net, mults, use_lsa, params)
+        budgets = net.f / params.rho
+        x_solo, val_solo = jax.vmap(solo_fn, in_axes=(1, 1, 0), out_axes=(1, 0))(
+            beta, queues.r, budgets)
 
-    def one_pair(j, k):
-        return pair_fn(
-            beta[:, j], gamma[:, k, j], beta[:, k], gamma[:, j, k],
-            queues.r[:, j], queues.r[:, k], budgets[j], budgets[k],
-            net.cap_d[j, k])
+        def one_pair(j, k):
+            return pair_fn(
+                beta[:, j], gamma[:, k, j], beta[:, k], gamma[:, j, k],
+                queues.r[:, j], queues.r[:, k], budgets[j], budgets[k],
+                net.cap_d[j, k])
 
-    pa = jax.vmap(one_pair)(pj_a, pk_a)
-    pair_vals = jnp.zeros((m, m), jnp.float32).at[pj_a, pk_a].set(pa.value)
-    pair_vals = pair_vals + pair_vals.T
+        pa = jax.vmap(one_pair)(pj_a, pk_a)
+        pair_vals = jnp.zeros((m, m), jnp.float32).at[pj_a, pk_a].set(pa.value)
+        pair_vals = pair_vals + pair_vals.T
 
     # Ragged padding: a masked EC must never be solo-selected nor paired (a
     # (real, padded) pair would otherwise shadow the real EC's solo option —
     # its value approximates the solo objective by a different solver). The
     # greedy path delegates the identical masking to the ops dispatch layer.
-    _, ec = entity_masks(params)
-    if exact:
-        from . import oracle
-        val_solo = jnp.where(ec > 0, val_solo, _NEG)
-        pair_vals = mask_pairs(pair_vals, ec, ec)
-        match = jnp.asarray(oracle.exact_pairing(np.asarray(val_solo), np.asarray(pair_vals)))
-    else:
-        match = matching_ops.greedy_pairing(val_solo, pair_vals, ec_mask=ec)
-
-    x, y, z = _compose_from_match(match, x_solo, (pj_a, pk_a), pa, m)
-    return x, y, z
+    with jax.named_scope("pairing"):
+        _, ec = entity_masks(params)
+        if exact:
+            from . import oracle
+            val_solo = jnp.where(ec > 0, val_solo, _NEG)
+            pair_vals = mask_pairs(pair_vals, ec, ec)
+            match = jnp.asarray(oracle.exact_pairing(np.asarray(val_solo),
+                                                     np.asarray(pair_vals)))
+        else:
+            match = matching_ops.greedy_pairing(val_solo, pair_vals, ec_mask=ec)
+        return _compose_from_match(match, x_solo, (pj_a, pk_a), pa, m)
 
 
 @TRAINING_POLICIES.register("skew")
@@ -383,19 +385,22 @@ def _train_linear(shape, params, net, mults, queues, exact, use_lsa):
 
 @TRAINING_POLICIES.register("solo")
 def _train_solo(shape, params, net, mults, queues, exact, use_lsa):
-    beta, _ = training_weights(shape, net, mults, use_lsa, params)
-    budgets = net.f / params.rho
-    x, _ = jax.vmap(training_alloc.solo_waterfill, in_axes=(1, 1, 0), out_axes=(1, 0))(
-        beta, queues.r, budgets)
+    with jax.named_scope("allocation"):
+        beta, _ = training_weights(shape, net, mults, use_lsa, params)
+        budgets = net.f / params.rho
+        x, _ = jax.vmap(training_alloc.solo_waterfill, in_axes=(1, 1, 0),
+                        out_axes=(1, 0))(beta, queues.r, budgets)
     m = shape.n_ec
     return x, jnp.zeros((shape.n_cu, m, m), jnp.float32), jnp.zeros((m, m), jnp.float32)
 
 
 @TRAINING_POLICIES.register("ecfull")
 def _train_ecfull(shape, params, net, mults, queues, exact, use_lsa):
-    beta, gamma = training_weights(shape, net, mults, use_lsa, params)
-    budgets = net.f / params.rho
-    x, y, _ = training_alloc.full_allocate(beta, gamma, queues.r, budgets, net.cap_d)
+    with jax.named_scope("allocation"):
+        beta, gamma = training_weights(shape, net, mults, use_lsa, params)
+        budgets = net.f / params.rho
+        x, y, _ = training_alloc.full_allocate(beta, gamma, queues.r, budgets,
+                                               net.cap_d)
     m = shape.n_ec
     _, ec = entity_masks(params)
     z = (jnp.ones((m, m), jnp.float32) - jnp.eye(m, dtype=jnp.float32))
@@ -531,8 +536,9 @@ def step(cfg: CocktailConfig | ShapeConfig, spec: AlgoSpec, state: SchedulerStat
     if net is None:
         # Per-slot noise from k_net; persistent heterogeneity from the
         # slot-invariant het_key the state carries unchanged.
-        net = sample_network_state(k_net, shape, state.t, params,
-                                   het_key=state.het_key)
+        with jax.named_scope("network"):
+            net = sample_network_state(k_net, shape, state.t, params,
+                                       het_key=state.het_key)
 
     switched = spec.switched
     if switched:
@@ -554,12 +560,14 @@ def step(cfg: CocktailConfig | ShapeConfig, spec: AlgoSpec, state: SchedulerStat
         else:
             eff = state.mults
 
+    # Training policies scope their own stages (allocation, pairing).
     if switched:
-        alpha, theta = jax.lax.switch(
-            params.collect_id,
-            [(lambda p, n, m, q, fn=fn: fn(shape, p, n, m, q, False))
-             for fn in COLLECTION_POLICIES.fns],
-            params, net, eff, state.queues)
+        with jax.named_scope("collection"):
+            alpha, theta = jax.lax.switch(
+                params.collect_id,
+                [(lambda p, n, m, q, fn=fn: fn(shape, p, n, m, q, False))
+                 for fn in COLLECTION_POLICIES.fns],
+                params, net, eff, state.queues)
         x, y, z = jax.lax.switch(
             params.train_id,
             [(lambda p, n, m, q, fn=fn: fn(shape, p, n, m, q, False, use_lsa))
@@ -568,47 +576,50 @@ def step(cfg: CocktailConfig | ShapeConfig, spec: AlgoSpec, state: SchedulerStat
     else:
         collect = COLLECTION_POLICIES[spec.collection]
         train = TRAINING_POLICIES[spec.training]
-        alpha, theta = collect(shape, params, net, eff, state.queues, spec.exact)
+        with jax.named_scope("collection"):
+            alpha, theta = collect(shape, params, net, eff, state.queues,
+                                   spec.exact)
         x, y, z = train(shape, params, net, eff, state.queues, spec.exact, use_lsa)
 
-    served = _served(alpha, theta, net, state.queues)
-    cost = framework_cost(net, served, x, y)
-    queues = apply_decision(shape, state.queues, net, served, x, y)
-    mults = update_multipliers(shape, state.mults, net, served, x, y,
-                               use_lsa, params.eps, params)
+    with jax.named_scope("update"):
+        served = _served(alpha, theta, net, state.queues)
+        cost = framework_cost(net, served, x, y)
+        queues = apply_decision(shape, state.queues, net, served, x, y)
+        mults = update_multipliers(shape, state.mults, net, served, x, y,
+                                   use_lsa, params.eps, params)
 
-    emp = state.emp_mults
-    if spec.learning_aid:
-        # Virtual decisions from plain P1/P2 with the empirical multipliers;
-        # they update Theta' only (diminishing step), never the real queues.
-        v_alpha, v_theta = _collect_plain(shape, params, net, state.emp_mults,
-                                          state.queues, False)
-        v_x, v_y, _ = _train_linear(shape, params, net, state.emp_mults,
-                                    state.queues, False, use_lsa)
-        v_served = _served(v_alpha, v_theta, net, state.queues)
-        sigma = params.sigma0 / jnp.sqrt(state.t.astype(jnp.float32) + 1.0)
-        emp = update_multipliers(shape, state.emp_mults, net, v_served, v_x, v_y,
-                                 use_lsa, sigma, params)
-        if switched:
-            # learning_aid gate: slices without the aid keep Theta' frozen.
-            emp = jax.tree.map(lambda new, old: jnp.where(aid, new, old),
-                               emp, state.emp_mults)
+        emp = state.emp_mults
+        if spec.learning_aid:
+            # Virtual decisions from plain P1/P2 with the empirical multipliers;
+            # they update Theta' only (diminishing step), never the real queues.
+            v_alpha, v_theta = _collect_plain(shape, params, net, state.emp_mults,
+                                              state.queues, False)
+            v_x, v_y, _ = _train_linear(shape, params, net, state.emp_mults,
+                                        state.queues, False, use_lsa)
+            v_served = _served(v_alpha, v_theta, net, state.queues)
+            sigma = params.sigma0 / jnp.sqrt(state.t.astype(jnp.float32) + 1.0)
+            emp = update_multipliers(shape, state.emp_mults, net, v_served, v_x, v_y,
+                                     use_lsa, sigma, params)
+            if switched:
+                # learning_aid gate: slices without the aid keep Theta' frozen.
+                emp = jax.tree.map(lambda new, old: jnp.where(aid, new, old),
+                                   emp, state.emp_mults)
 
-    trained = jnp.sum(x) + jnp.sum(y)
-    new_state = SchedulerState(
-        queues=queues, mults=mults, emp_mults=emp,
-        t=state.t + 1,
-        total_cost=state.total_cost + cost,
-        total_trained=state.total_trained + trained,
-        uploaded=state.uploaded + jnp.sum(served, axis=1),
-        rng=rng,
-        het_key=state.het_key,
-    )
-    rec = SlotRecord(
-        cost=cost, trained=trained,
-        q_backlog=jnp.sum(queues.q), r_backlog=jnp.sum(queues.r),
-        skew=skew_degree(shape, queues.omega, params),
-    )
+        trained = jnp.sum(x) + jnp.sum(y)
+        new_state = SchedulerState(
+            queues=queues, mults=mults, emp_mults=emp,
+            t=state.t + 1,
+            total_cost=state.total_cost + cost,
+            total_trained=state.total_trained + trained,
+            uploaded=state.uploaded + jnp.sum(served, axis=1),
+            rng=rng,
+            het_key=state.het_key,
+        )
+        rec = SlotRecord(
+            cost=cost, trained=trained,
+            q_backlog=jnp.sum(queues.q), r_backlog=jnp.sum(queues.r),
+            skew=skew_degree(shape, queues.omega, params),
+        )
     dec = Decision(alpha=alpha, theta=theta, x=x, y=y, z=z)
     return new_state, rec, dec
 

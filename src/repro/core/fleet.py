@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+from .. import obs
 from .datasche import AlgoSpec, DS, SWITCHED, SWITCHED_NOAID, SlotRecord, step
 from .job import JobLike, SliceJob, as_jobs
 from .types import (CocktailConfig, Decision, Multipliers, QueueState,
@@ -193,30 +194,32 @@ class FleetEngine:
         ``lax.switch``), so the whole fleet is still ONE compiled program.
         Bare ``CocktailConfig`` entries are accepted and get ``spec``.
         """
-        jobs = as_jobs(jobs, spec)
-        if not jobs:
-            raise ValueError("need at least one SliceJob")
-        pad = ragged_pad_shape([j.shape for j in jobs])
-        policies = {(j.spec.collection, j.spec.training, j.spec.use_lsa,
-                     j.spec.learning_aid) for j in jobs}
-        # Distinct specs with identical policy tuples (e.g. DS vs GREEDY)
-        # still compile one static program — switch only when policies differ.
-        # The policy leaves are filled either way, so the params always state
-        # what each slice runs (static dispatch just ignores them). Mixed
-        # fleets without an L-DS slice get the virtual path compiled out.
-        mixed = len(policies) > 1
-        any_aid = any(j.spec.learning_aid for j in jobs)
-        switch_spec = SWITCHED if any_aid else SWITCHED_NOAID
-        return cls(
-            shape=pad,
-            spec=switch_spec if mixed else jobs[0].spec,
-            params=stack_slice_params(
-                [j.params(pad_shape=pad, policy_leaves=True) for j in jobs]),
-            n_slices=len(jobs),
-            seeds=tuple(j.resolved_seed for j in jobs),
-            slice_shapes=tuple(j.shape for j in jobs),
-            slice_specs=tuple(j.spec for j in jobs),
-        )
+        with obs.span("fleet.from_jobs"):
+            jobs = as_jobs(jobs, spec)
+            if not jobs:
+                raise ValueError("need at least one SliceJob")
+            pad = ragged_pad_shape([j.shape for j in jobs])
+            policies = {(j.spec.collection, j.spec.training, j.spec.use_lsa,
+                         j.spec.learning_aid) for j in jobs}
+            # Distinct specs with identical policy tuples (e.g. DS vs
+            # GREEDY) still compile one static program — switch only when
+            # policies differ. The policy leaves are filled either way, so
+            # the params always state what each slice runs (static dispatch
+            # just ignores them). Mixed fleets without an L-DS slice get the
+            # virtual path compiled out.
+            mixed = len(policies) > 1
+            any_aid = any(j.spec.learning_aid for j in jobs)
+            switch_spec = SWITCHED if any_aid else SWITCHED_NOAID
+            return cls(
+                shape=pad,
+                spec=switch_spec if mixed else jobs[0].spec,
+                params=stack_slice_params(
+                    [j.params(pad_shape=pad, policy_leaves=True) for j in jobs]),
+                n_slices=len(jobs),
+                seeds=tuple(j.resolved_seed for j in jobs),
+                slice_shapes=tuple(j.shape for j in jobs),
+                slice_specs=tuple(j.spec for j in jobs),
+            )
 
     @classmethod
     def from_configs(cls, configs: Sequence[CocktailConfig],
@@ -253,9 +256,11 @@ class FleetEngine:
 
     def init(self) -> SchedulerState:
         """Stacked initial state: slice k gets params[k] and PRNGKey(seeds[k])."""
-        states = [init_state(self.shape, unstack(self.params, k), seed=self.seeds[k])
-                  for k in range(self.n_slices)]
-        return jax.tree.map(lambda *ls: jnp.stack(ls), *states)
+        with obs.span("fleet.init"):
+            states = [init_state(self.shape, unstack(self.params, k),
+                                 seed=self.seeds[k])
+                      for k in range(self.n_slices)]
+            return jax.tree.map(lambda *ls: jnp.stack(ls), *states)
 
     def slice_state(self, state: SchedulerState, k: int) -> SchedulerState:
         """Slice k's SchedulerState (for per-slice metrics.summary etc.).
@@ -286,11 +291,13 @@ class FleetEngine:
         Returns (stacked final state (K, ...), stacked records (T, K)).
         With ``mesh``, the K axis of params/state is sharded over
         ``mesh[axis_name]`` before the scan (K % axis size must be 0) and
-        each device runs the slot program on its own slices.
+        each device runs the slot program on its own slices. The call
+        returns once the program is dispatched (span ``fleet.run``).
         """
-        return _fleet_scan(self.shape, self.spec, n_slots,
-                           *self._placed(state, mesh, axis_name), mesh=mesh,
-                           axis_name=axis_name)
+        with obs.span("fleet.run"):
+            return _fleet_scan(self.shape, self.spec, n_slots,
+                               *self._placed(state, mesh, axis_name),
+                               mesh=mesh, axis_name=axis_name)
 
     def lower(self, n_slots: int, state: Optional[SchedulerState] = None,
               mesh=None, axis_name: str = "data"):

@@ -43,6 +43,12 @@ def solo_waterfill(beta: jax.Array, r: jax.Array, budget: jax.Array) -> tuple[ja
     max sum_{i active} log(beta_i x_i)  s.t. sum x <= budget, 0 <= x_i <= r_i,
     active = {beta_i > 0, r_i > 0}. Optimal x_i = min(r_i, w) with the water
     level w chosen to exhaust min(budget, sum r_active).
+
+    On the caps sorted ascending, w is the candidate level (fill - k smallest
+    caps) / (n_act - k) of the first k that does not pass the k-th cap. When
+    none stops below a cap (the budget covers all active data, up to
+    rounding), w = inf and every active CU trains all it holds. No tolerance
+    enters: rounding moves the level by rounding, never to 0.
     """
     n = beta.shape[0]
     active = (beta > 0) & (r > _TINY)
@@ -56,12 +62,8 @@ def solo_waterfill(beta: jax.Array, r: jax.Array, budget: jax.Array) -> tuple[ja
     k = jnp.arange(n)
     denom = jnp.maximum((n_act - k).astype(r.dtype), 1.0)
     w_k = (fill - cs) / denom
-    s_prev = jnp.concatenate([jnp.zeros((1,), s.dtype), s])[:-1]
-    valid = (k < n_act) & (w_k >= s_prev - 1e-6) & (w_k <= s + 1e-6)
-    # If sum r_active <= budget the level is max(r) and k = n_act-1 is valid.
-    any_valid = jnp.any(valid)
-    k_star = jnp.argmax(valid)  # first valid segment
-    level = jnp.where(any_valid, w_k[k_star], 0.0)
+    stops = (k < n_act) & (w_k <= s)
+    level = jnp.where(jnp.any(stops), w_k[jnp.argmax(stops)], jnp.inf)
     x = jnp.where(active, jnp.minimum(r, jnp.maximum(level, 0.0)), 0.0)
     pos = x > _TINY
     value = jnp.sum(jnp.where(pos, jnp.log(jnp.maximum(beta * x, _TINY)), 0.0))

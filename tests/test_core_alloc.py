@@ -4,6 +4,7 @@ import pytest
 pytest.importorskip("hypothesis")  # optional dev dep; see requirements-dev.txt
 from hypothesis import given, settings, strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import training_alloc as ta
@@ -76,6 +77,50 @@ class TestSoloWaterfill:
         r = jnp.asarray([3.0, 5.0])
         x, _ = ta.solo_waterfill(beta, r, jnp.asarray(100.0))
         np.testing.assert_allclose(np.asarray(x), [3.0, 5.0], rtol=1e-5)
+
+
+def _waterfill_draws(scale, draws=500, n=20, seed=11):
+    """20 CUs with random caps of thousands of samples, some inactive, and
+    the budget at ``scale`` times the active data."""
+    rng = np.random.default_rng(seed)
+    beta = rng.uniform(-1.0, 5.0, (draws, n)).astype(np.float32)
+    r = rng.uniform(0.0, 3000.0, (draws, n)).astype(np.float32)
+    r[rng.uniform(size=(draws, n)) < 0.2] = 0.0
+    active = (beta > 0) & (r > 1e-9)
+    budget = (scale * np.where(active, r, 0.0).sum(axis=1)).astype(np.float32)
+    x, _ = jax.jit(jax.vmap(ta.solo_waterfill))(jnp.asarray(beta), jnp.asarray(r),
+                                                 jnp.asarray(budget))
+    return np.asarray(x), r, budget, active
+
+
+@pytest.mark.parametrize("scale", [1.0, 1.5])
+def test_waterfill_trains_all_when_budget_covers_data(scale):
+    """A budget that covers all active data trains all of it: rounding of
+    the candidate level must not drop the EC's training (it once gave a
+    level of 0 in about a third of such draws). The level at the largest
+    cap is a difference of float32 sums, so it may miss that cap by a few
+    ulps of the EC's total data, never by more."""
+    x, r, _, active = _waterfill_draws(scale)
+    assert active.any(axis=1).all()
+    total = np.where(active, r, 0.0).sum(axis=1, dtype=np.float32)
+    ulps = np.broadcast_to(np.spacing(total)[:, None], r.shape)
+    assert (np.abs(x - r)[active] <= 4 * ulps[active]).all()
+    assert (x[~active] == 0).all()
+
+
+def test_waterfill_binding_budget_matches_closed_form():
+    """At half the active data the level is the closed form: on the sorted
+    caps, the first k whose (budget - k smallest) / (n_act - k) does not
+    pass the k-th cap (float64 in NumPy)."""
+    x, r, budget, active = _waterfill_draws(0.5)
+    for xi, ri, bi, ai in zip(x, r, budget, active):
+        caps = np.sort(ri[ai].astype(np.float64))
+        below = np.concatenate([[0.0], np.cumsum(caps)[:-1]])
+        level_k = (float(bi) - below) / (caps.size - np.arange(caps.size))
+        level = level_k[np.argmax(level_k <= caps)]
+        want = np.where(ai, np.minimum(ri, level), 0.0)
+        np.testing.assert_allclose(xi, want, rtol=1e-5, atol=1e-3)
+        assert xi.sum() == pytest.approx(float(bi), rel=1e-5)
 
 
 def _pair_instance(rng, n):
