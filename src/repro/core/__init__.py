@@ -13,6 +13,9 @@ Public API:
                                           homogeneous, ragged mixed-shape
                                           (padding + entity masks) and
                                           mixed-policy fleets in ONE program
+  PackedState, PackedRecord            -- what FleetEngine.run returns: the
+                                          state and records, one buffer
+                                          each, read like the trees
   metrics                              -- Sec. IV evaluation metrics
 """
 from .datasche import (ALL_SPECS, COLLECTION_POLICIES, CU_FULL, DS, DS_EXACT,
@@ -21,7 +24,8 @@ from .datasche import (ALL_SPECS, COLLECTION_POLICIES, CU_FULL, DS, DS_EXACT,
                        PolicyTable, SlotRecord, collection_weights, run,
                        skew_degree, stack_slot_records, step, training_weights,
                        with_policy)
-from .fleet import FleetEngine, ragged_pad_shape, trim_state
+from .fleet import (FleetEngine, PackedRecord, PackedState, ragged_pad_shape,
+                    trim_state)
 from .job import SliceJob, as_jobs
 from .network import framework_cost, sample_network_state
 from .types import (MASKED_WEIGHT, CocktailConfig, Decision, Multipliers,
@@ -33,7 +37,8 @@ __all__ = [
     "ALL_SPECS", "AlgoSpec", "CocktailConfig", "COLLECTION_POLICIES",
     "CU_FULL", "DS", "DS_EXACT", "Decision", "EC_FULL", "EC_SELF",
     "FleetEngine", "GREEDY", "LDS", "Multipliers", "NetworkState", "NO_LSA",
-    "NO_SDC", "NO_SLT", "PolicyTable", "QueueState", "SWITCHED",
+    "NO_SDC", "NO_SLT", "PackedRecord", "PackedState", "PolicyTable",
+    "QueueState", "SWITCHED",
     "SWITCHED_NOAID",
     "SchedulerState", "ShapeConfig", "SliceJob", "SliceParams", "SlotRecord",
     "TRAINING_POLICIES", "MASKED_WEIGHT", "as_jobs", "collection_weights",

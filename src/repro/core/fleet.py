@@ -10,11 +10,27 @@ is just that pytree with a leading K axis, and one slot of the whole fleet is
 
 Axis conventions (documented in ROADMAP.md):
   * stacked ``SliceParams`` / ``SchedulerState``: leading axis = slice (K)
-  * stacked ``SlotRecord`` returned by :meth:`FleetEngine.run`: time-major
-    (T, K) — axis 0 is the slot, matching single-slice ``run``'s (T,)
+  * records of a run: time-major (T, K) — axis 0 is the slot, matching
+    single-slice ``run``'s (T,)
   * optional device sharding splits the K axis over a mesh axis via
     ``launch.mesh.shard_leading_axis`` (NamedSharding, trailing axes
     replicated)
+
+The slot program's boundary is packed: one buffer in per side and one out.
+:meth:`FleetEngine.run` passes the parameters as one ``(K, Wp)`` ``uint32``
+buffer (``PackedTree``, packed once per engine) and the carried state as one
+``(K, Ws)`` ``uint32`` buffer (``PackedState``), and gets back the next
+``PackedState`` and the records as one ``(T, K, F)`` ``float32`` buffer
+(``PackedRecord``, F = the ``SlotRecord`` fields in order). Each leaf of a
+packed tree is bitcast to ``uint32`` and flattened per slice, in the tree's
+leaf order; the layout (treedef, per-slice shape and dtype of each leaf) is
+static pytree data, read from the tree itself. K stays axis 0 (axis 1 of the
+records), so sharding and ``shard_map`` see the same leading axis as on the
+tree. ``PackedState`` and ``PackedRecord`` read like the trees they hold
+(``state.queues.q``, ``recs.cost``), on device arrays and on the numpy
+arrays ``jax.device_get`` leaves; ``.tree()`` gives the tree itself. A tree
+state given to ``run`` is packed once (span ``fleet.pack``); the state
+``run`` returns is packed already, so a chain of calls never packs again.
 
 Constraints: all slices of a fleet run at one *compiled* ``ShapeConfig`` (N,
 M and solver iteration counts are compile-time); ``exact`` specs are
@@ -38,10 +54,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Sequence
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec
 
 from .. import obs
@@ -52,14 +69,163 @@ from .types import (CocktailConfig, Decision, Multipliers, QueueState,
                     split_config, stack_slice_params)
 
 
+# -- the packed boundary ------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _Layout:
+    """Where each leaf of a pytree lies in its packed ``(K, W)`` buffer:
+    the treedef, and each leaf's per-slice shape, dtype and first column."""
+
+    treedef: Any
+    shapes: tuple[tuple[int, ...], ...]
+    dtypes: tuple[np.dtype, ...]
+    offsets: tuple[int, ...]  # len(shapes) + 1 column bounds
+
+    @classmethod
+    def of(cls, tree) -> "_Layout":
+        leaves, treedef = jax.tree.flatten(tree)
+        dtypes = tuple(np.dtype(l.dtype) for l in leaves)
+        shapes = tuple(tuple(l.shape[1:]) for l in leaves)
+        offsets = np.cumsum([0] + [int(np.prod(s)) for s in shapes])
+        return cls(treedef, shapes, dtypes, tuple(int(o) for o in offsets))
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedTree:
+    """A pytree of 32-bit leaves with a leading slice axis K, held as one
+    ``(K, W)`` ``uint32`` buffer: each leaf bitcast to ``uint32`` and
+    flattened per slice, in leaf order. The buffer is the only pytree leaf;
+    the layout is static. ``tree()`` gives the tree back, bit for bit."""
+
+    __slots__ = ("buf", "layout")
+
+    def __init__(self, buf, layout: _Layout):
+        self.buf, self.layout = buf, layout
+
+    def tree_flatten(self):
+        return (self.buf,), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(children[0], layout)
+
+    @classmethod
+    def pack(cls, tree):
+        """Pack ``tree`` (traceable; run under jit, see ``_pack``)."""
+        words = [x if x.dtype == jnp.uint32
+                 else jax.lax.bitcast_convert_type(x, jnp.uint32)
+                 for x in map(jnp.asarray, jax.tree.leaves(tree))]
+        k = words[0].shape[0]
+        return cls(jnp.concatenate([w.reshape(k, -1) for w in words], axis=1),
+                   _Layout.of(tree))
+
+    def _leaf(self, i: int):
+        lo, hi = self.layout.offsets[i], self.layout.offsets[i + 1]
+        words, dtype = self.buf[:, lo:hi], self.layout.dtypes[i]
+        if isinstance(words, np.ndarray):
+            x = words.view(dtype)
+        elif dtype == np.uint32:
+            x = words
+        else:
+            x = jax.lax.bitcast_convert_type(words, dtype)
+        return x.reshape(x.shape[:1] + self.layout.shapes[i])
+
+    def tree(self):
+        n = len(self.layout.shapes)
+        return jax.tree.unflatten(self.layout.treedef,
+                                  [self._leaf(i) for i in range(n)])
+
+
+def _field_property(name: str) -> property:
+    return property(lambda self: getattr(self.tree(), name),
+                    doc=f"``SchedulerState.{name}``, unpacked")
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedState(PackedTree):
+    """A stacked ``SchedulerState`` packed as one ``(K, Ws)`` ``uint32``
+    buffer: what :meth:`FleetEngine.run` returns and takes back. Its fields
+    read as the tree's (``state.queues.q``, ``state.rng``)."""
+
+    __slots__ = ()
+
+    queues = _field_property("queues")
+    mults = _field_property("mults")
+    emp_mults = _field_property("emp_mults")
+    t = _field_property("t")
+    total_cost = _field_property("total_cost")
+    total_trained = _field_property("total_trained")
+    uploaded = _field_property("uploaded")
+    rng = _field_property("rng")
+    het_key = _field_property("het_key")
+
+
+def _column_property(name: str) -> property:
+    i = SlotRecord._fields.index(name)
+    return property(lambda self: self.buf[..., i],
+                    doc=f"``SlotRecord.{name}``, (T, K)")
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedRecord:
+    """Time-major ``SlotRecord``s as one ``(T, K, F)`` ``float32`` buffer,
+    F = the record fields in order; each field reads as its ``(T, K)``
+    array (``recs.cost``)."""
+
+    __slots__ = ("buf",)
+    _fields = SlotRecord._fields
+
+    def __init__(self, buf):
+        self.buf = buf
+
+    def tree_flatten(self):
+        return (self.buf,), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(children[0])
+
+    @classmethod
+    def pack(cls, rec: SlotRecord) -> "PackedRecord":
+        return cls(jnp.stack(rec, axis=-1))
+
+    def tree(self) -> SlotRecord:
+        return SlotRecord(*(self.buf[..., i] for i in range(len(self._fields))))
+
+    def _replace(self, **fields) -> "PackedRecord":
+        """As ``SlotRecord._replace``: the record with some fields replaced."""
+        return PackedRecord.pack(self.tree()._replace(**fields))
+
+    cost = _column_property("cost")
+    trained = _column_property("trained")
+    q_backlog = _column_property("q_backlog")
+    r_backlog = _column_property("r_backlog")
+    skew = _column_property("skew")
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _pack(cls, tree):
+    """``cls.pack(tree)`` as one small program (one compile per layout)."""
+    return cls.pack(tree)
+
+
+def _as_tree(x):
+    """The tree of a packed value; a tree as it is."""
+    return x.tree() if isinstance(x, (PackedTree, PackedRecord)) else x
+
+
+# -- tree helpers -------------------------------------------------------------
+
 def unstack(tree, k: int):
-    """Extract slice k from a stacked (K, ...) pytree (state, params)."""
-    return jax.tree.map(lambda l: l[k], tree)
+    """Extract slice k from a stacked (K, ...) pytree (state, params), packed
+    or not; the result is a tree."""
+    return jax.tree.map(lambda l: l[k], _as_tree(tree))
 
 
-def slice_records(recs: SlotRecord, k: int) -> SlotRecord:
-    """Slice k's (T,) per-slot trace out of time-major (T, K) fleet records."""
-    return jax.tree.map(lambda l: l[:, k], recs)
+def slice_records(recs, k: int) -> SlotRecord:
+    """Slice k's (T,) per-slot trace out of time-major (T, K) fleet records
+    (a ``SlotRecord`` or a ``PackedRecord``)."""
+    return jax.tree.map(lambda l: l[:, k], _as_tree(recs))
 
 
 def ragged_pad_shape(shapes: Sequence[ShapeConfig]) -> ShapeConfig:
@@ -74,50 +240,64 @@ def ragged_pad_shape(shapes: Sequence[ShapeConfig]) -> ShapeConfig:
                       pair_iters=iters.pop())
 
 
-def trim_state(state: SchedulerState, shape: ShapeConfig) -> SchedulerState:
-    """Drop the ragged padding of one slice's state: slice every entity axis
-    down to the true (N, M). Padded entries are exactly zero by the mask
-    invariants, so this is lossless."""
-    n, m = shape.n_cu, shape.n_ec
+def trim_state(state, shape: ShapeConfig) -> SchedulerState:
+    """Drop the ragged padding of a state: slice every entity axis down to
+    the true (N, M). The state is one slice's tree, or a stacked tree or
+    ``PackedState`` whose leading slice axis is kept (every slice trimmed
+    to ``shape``). Padded entries are exactly zero by the mask invariants,
+    so this is lossless."""
+    state = _as_tree(state)
+    lead = (slice(None),) * (jnp.ndim(state.queues.q) - 1)
+    cu = lead + (slice(shape.n_cu),)
+    pair = cu + (slice(shape.n_ec),)
 
     def trim_mults(mu: Multipliers) -> Multipliers:
-        return Multipliers(mu=mu.mu[:n], eta=mu.eta[:n, :m],
-                           phi=mu.phi[:n, :m], lam=mu.lam[:n, :m])
+        return Multipliers(mu=mu.mu[cu], eta=mu.eta[pair],
+                           phi=mu.phi[pair], lam=mu.lam[pair])
 
     return state._replace(
-        queues=QueueState(q=state.queues.q[:n], r=state.queues.r[:n, :m],
-                          omega=state.queues.omega[:n, :m]),
+        queues=QueueState(q=state.queues.q[cu], r=state.queues.r[pair],
+                          omega=state.queues.omega[pair]),
         mults=trim_mults(state.mults),
         emp_mults=trim_mults(state.emp_mults),
-        uploaded=state.uploaded[:n],
+        uploaded=state.uploaded[cu],
     )
+
+
+def scan_slots(shape: ShapeConfig, spec: AlgoSpec, n_slots: int,
+               params: SliceParams, state: SchedulerState
+               ) -> tuple[SchedulerState, SlotRecord]:
+    """The slot program on trees: ``vmap(step)`` over the K slices inside
+    one scan. Records come back time-major (T, K)."""
+    def one_slot(p, s):
+        s2, rec, _ = step(shape, spec, s, params=p)
+        return s2, rec
+
+    vstep = jax.vmap(one_slot)
+
+    def body(s, _):
+        return vstep(params, s)
+
+    return jax.lax.scan(body, state, None, length=n_slots)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2),
                    static_argnames=("mesh", "axis_name"))
 def _fleet_scan(shape: ShapeConfig, spec: AlgoSpec, n_slots: int,
-                params: SliceParams, state: SchedulerState, mesh=None,
+                params: PackedTree, state: PackedState, mesh=None,
                 axis_name: str = "data"
-                ) -> tuple[SchedulerState, SlotRecord]:
-    """The slot program: ``vmap(step)`` over the K slices inside one scan.
+                ) -> tuple[PackedState, PackedRecord]:
+    """The slot program on the packed boundary: unpack, :func:`scan_slots`,
+    pack. Two buffers in, two out.
 
     With ``mesh``, the program runs per shard of the K axis
     (``jax.shard_map``): slices are independent, so each device scans its
     own K / n slices with no communication. This is also what lets the
     Pallas matchers run sharded: Mosaic kernels are not partitioned
     automatically."""
-    def one_slot(p, s):
-        s2, rec, _ = step(shape, spec, s, params=p)
-        return s2, rec
-
     def scan(params, state):
-        vstep = jax.vmap(one_slot)
-
-        def body(s, _):
-            s2, rec = vstep(params, s)
-            return s2, rec
-
-        return jax.lax.scan(body, state, None, length=n_slots)
+        s2, rec = scan_slots(shape, spec, n_slots, params.tree(), state.tree())
+        return PackedState.pack(s2), PackedRecord.pack(rec)
 
     if mesh is None:
         return scan(params, state)
@@ -262,8 +442,9 @@ class FleetEngine:
                       for k in range(self.n_slices)]
             return jax.tree.map(lambda *ls: jnp.stack(ls), *states)
 
-    def slice_state(self, state: SchedulerState, k: int) -> SchedulerState:
-        """Slice k's SchedulerState (for per-slice metrics.summary etc.).
+    def slice_state(self, state, k: int) -> SchedulerState:
+        """Slice k's SchedulerState (for per-slice metrics.summary etc.),
+        from a stacked tree or a ``PackedState``.
 
         Ragged fleets: the padding is trimmed back off, so the result has the
         slice's true (N, M) and drops straight into shape-aware consumers
@@ -275,32 +456,36 @@ class FleetEngine:
 
     # -- execution --------------------------------------------------------
 
-    def step(self, state: SchedulerState
-             ) -> tuple[SchedulerState, SlotRecord, Decision]:
+    def step(self, state) -> tuple[SchedulerState, SlotRecord, Decision]:
         """One fleet slot (eager vmap; prefer :meth:`run` for loops)."""
         new_state, rec, dec = jax.vmap(
             lambda p, s: step(self.shape, self.spec, s, params=p)
-        )(self.params, state)
+        )(self.params, _as_tree(state))
         return new_state, rec, dec
 
-    def run(self, n_slots: int, state: Optional[SchedulerState] = None,
-            mesh=None, axis_name: str = "data"
-            ) -> tuple[SchedulerState, SlotRecord]:
+    def run(self, n_slots: int,
+            state: "PackedState | SchedulerState | None" = None, mesh=None,
+            axis_name: str = "data") -> tuple[PackedState, PackedRecord]:
         """Run the whole fleet for n_slots inside one jitted scan.
 
-        Returns (stacked final state (K, ...), stacked records (T, K)).
-        With ``mesh``, the K axis of params/state is sharded over
-        ``mesh[axis_name]`` before the scan (K % axis size must be 0) and
-        each device runs the slot program on its own slices. The call
-        returns once the program is dispatched (span ``fleet.run``).
+        ``state`` is a ``PackedState`` (what ``run`` returns), a stacked
+        ``SchedulerState`` (packed first, span ``fleet.pack``) or None (the
+        initial state). Returns (the final state as a ``PackedState`` (K,
+        ...), the records as a ``PackedRecord`` (T, K)); both read like
+        the trees, and ``.tree()`` gives the trees. With ``mesh``, the K
+        axis of params/state is sharded over ``mesh[axis_name]`` before the
+        scan (K % axis size must be 0) and each device runs the slot
+        program on its own slices. The call returns once the program is
+        dispatched (span ``fleet.run``).
         """
         with obs.span("fleet.run"):
             return _fleet_scan(self.shape, self.spec, n_slots,
                                *self._placed(state, mesh, axis_name),
                                mesh=mesh, axis_name=axis_name)
 
-    def lower(self, n_slots: int, state: Optional[SchedulerState] = None,
-              mesh=None, axis_name: str = "data"):
+    def lower(self, n_slots: int,
+              state: "PackedState | SchedulerState | None" = None, mesh=None,
+              axis_name: str = "data"):
         """The slot program :meth:`run` executes, lowered but not run:
         ``.compile()`` gives the executable (a cache hit after a ``run`` of
         the same shapes) and ``.compile().as_text()`` its HLO."""
@@ -308,12 +493,21 @@ class FleetEngine:
                                  *self._placed(state, mesh, axis_name),
                                  mesh=mesh, axis_name=axis_name)
 
+    @functools.cached_property
+    def packed_params(self) -> PackedTree:
+        """``params`` as the slot program takes them, packed once."""
+        return _pack(PackedTree, self.params)
+
     def _placed(self, state, mesh, axis_name):
-        """(params, state) as the slot program takes them: the initial state
-        by default, the K axis sharded over ``mesh[axis_name]`` with a mesh."""
+        """(params, state) packed as the slot program takes them: the
+        initial state by default, the K axis sharded over
+        ``mesh[axis_name]`` with a mesh."""
         if state is None:
             state = self.init()
-        params = self.params
+        if not isinstance(state, PackedState):
+            with obs.span("fleet.pack"):
+                state = _pack(PackedState, state)
+        params = self.packed_params
         if mesh is not None:
             from ..launch.mesh import shard_leading_axis
             params = shard_leading_axis(params, mesh, axis_name)

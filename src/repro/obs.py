@@ -18,6 +18,10 @@ Spans in the program:
   fleet.run                     one ``FleetEngine.run`` call: placing the
                                 arguments and dispatching the slot program
                                 (it returns before the device is done)
+  fleet.pack                    packing a tree state into the slot
+                                program's one state buffer, inside
+                                ``FleetEngine.run`` or ``.lower``: once per
+                                chain of calls that starts from a tree
 """
 from __future__ import annotations
 
