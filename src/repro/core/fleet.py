@@ -240,6 +240,21 @@ def ragged_pad_shape(shapes: Sequence[ShapeConfig]) -> ShapeConfig:
                       pair_iters=iters.pop())
 
 
+def _pairs(m: int) -> int:
+    return m * (m - 1) // 2
+
+
+def _gauge_padding(shapes: Sequence[ShapeConfig], pad: ShapeConfig) -> None:
+    """How much of the compiled slot is ragged padding: the CU-EC links and
+    EC-pair problems of the true shapes against those the program computes
+    at ``pad`` (gauges ``fleet.links_*``, ``fleet.pairs_*``)."""
+    k = len(shapes)
+    obs.gauge("fleet.links_real", sum(s.n_cu * s.n_ec for s in shapes))
+    obs.gauge("fleet.links_compiled", k * pad.n_cu * pad.n_ec)
+    obs.gauge("fleet.pairs_real", sum(_pairs(s.n_ec) for s in shapes))
+    obs.gauge("fleet.pairs_compiled", k * _pairs(pad.n_ec))
+
+
 def trim_state(state, shape: ShapeConfig) -> SchedulerState:
     """Drop the ragged padding of a state: slice every entity axis down to
     the true (N, M). The state is one slice's tree, or a stacked tree or
@@ -373,6 +388,8 @@ class FleetEngine:
         under branch-free ``SWITCHED`` dispatch (policy leaves +
         ``lax.switch``), so the whole fleet is still ONE compiled program.
         Bare ``CocktailConfig`` entries are accepted and get ``spec``.
+        Sets the padding gauges ``fleet.links_*``/``fleet.pairs_*``
+        (``repro.obs``) for the fleet it builds.
         """
         with obs.span("fleet.from_jobs"):
             jobs = as_jobs(jobs, spec)
@@ -390,6 +407,7 @@ class FleetEngine:
             mixed = len(policies) > 1
             any_aid = any(j.spec.learning_aid for j in jobs)
             switch_spec = SWITCHED if any_aid else SWITCHED_NOAID
+            _gauge_padding([j.shape for j in jobs], pad)
             return cls(
                 shape=pad,
                 spec=switch_spec if mixed else jobs[0].spec,

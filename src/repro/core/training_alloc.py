@@ -130,9 +130,12 @@ def pair_allocate(
             jnp.sum(x_j + y_kj),
             jnp.sum(x_k + y_jk),
         ])
-        grad = (use - cap) / (cap + 1.0)
         step = 0.5 / jnp.sqrt(t + 1.0)
-        return jnp.maximum(duals + step * grad, 0.0)
+        # Rounded in this order, as the plain reference rounds it
+        # (bench/reference/cocktail_slot.pair_alloc): a price driven near 0
+        # makes the primal 1/price amplify one rounding step into another
+        # allocation, so the order is part of the result.
+        return jnp.maximum(duals + step * (use - cap) / (cap + 1.0), 0.0)
 
     duals0 = jnp.ones((3,), jnp.float32) * 0.01
     duals = jax.lax.fori_loop(0, iters, dual_step, duals0)

@@ -1,4 +1,4 @@
-"""The program's own spans and compile counter, always on.
+"""The program's own spans, gauges and compile counter, always on.
 
 ``span(name)`` times a block of host code twice: as a
 ``jax.profiler.TraceAnnotation``, so it lands on the host plane of a profiler
@@ -22,6 +22,17 @@ Spans in the program:
                                 program's one state buffer, inside
                                 ``FleetEngine.run`` or ``.lower``: once per
                                 chain of calls that starts from a tree
+
+``gauge(name, value)`` keeps the last value set under ``name``; ``gauges()``
+returns a copy of them all. Gauges in the program, set once per fleet by
+``FleetEngine.from_jobs`` (never on the slot path):
+
+  fleet.links_real       sum over slices of N_k * M_k (true CU-EC links)
+  fleet.links_compiled   K * N_pad * M_pad (links the slot program computes)
+  fleet.pairs_real       sum over slices of M_k (M_k - 1) / 2 (EC-pair
+                         problems of the true shapes)
+  fleet.pairs_compiled   K * M_pad (M_pad - 1) / 2 (pair problems the slot
+                         program solves)
 """
 from __future__ import annotations
 
@@ -41,6 +52,7 @@ COMPILE_EVENTS = (TRACE, LOWER, COMPILE, CACHE_LOAD)
 _spans: dict[str, collections.deque] = collections.defaultdict(
     lambda: collections.deque(maxlen=RING))
 _compiles: collections.deque = collections.deque(maxlen=RING)
+_gauges: dict[str, float] = {}
 
 
 class span:
@@ -70,6 +82,16 @@ def spans(name: str) -> list[tuple[int, int]]:
     return list(_spans.get(name, ()))
 
 
+def gauge(name: str, value: float) -> None:
+    """Set the gauge ``name`` to ``value`` (the last value is kept)."""
+    _gauges[name] = value
+
+
+def gauges() -> dict[str, float]:
+    """A copy of every gauge, by name."""
+    return dict(_gauges)
+
+
 def compile_events() -> list[tuple[int, str, float]]:
     """``(end_ns, event, seconds)`` of the newest ``RING`` compile phases."""
     return list(_compiles)
@@ -78,6 +100,7 @@ def compile_events() -> list[tuple[int, str, float]]:
 def reset() -> None:
     _spans.clear()
     _compiles.clear()
+    _gauges.clear()
 
 
 def _on_duration(event: str, seconds: float, **_) -> None:

@@ -133,3 +133,26 @@ def test_decision_assembly_runs_at_highest_precision(paper):
     assert len(assembly) == 6  # 2 two-operand einsums + 2 three-operand (2 dots each)
     for line in assembly:
         assert "precision = [HIGHEST, HIGHEST]" in line, line
+
+
+def test_gauge_keeps_the_last_value_and_reset_clears_it():
+    obs.reset()
+    obs.gauge("test.g", 3)
+    obs.gauge("test.g", 5)
+    assert obs.gauges() == {"test.g": 5}
+    obs.gauges()["test.g"] = 7  # a copy
+    assert obs.gauges() == {"test.g": 5}
+    obs.reset()
+    assert obs.gauges() == {}
+
+
+def test_from_jobs_gauges_a_uniform_fleet_as_all_real():
+    """Eight slices of the paper's 20 x 5: nothing is padding."""
+    obs.reset()
+    c, sl = PAPER, PAPER["slice"]
+    FleetEngine.from_jobs([SliceJob(CocktailConfig(
+        n_cu=c["n_cu"], n_ec=c["n_ec"], pair_iters=c["pair_iters"],
+        f_base=tuple(c["f_base"]), seed=s, **sl), spec=DS) for s in range(8)])
+    assert obs.gauges() == {"fleet.links_real": 800, "fleet.links_compiled": 800,
+                            "fleet.pairs_real": 80, "fleet.pairs_compiled": 80}
+    obs.reset()

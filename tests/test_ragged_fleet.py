@@ -190,6 +190,25 @@ def test_ragged_rejects_mismatched_pair_iters():
         FleetEngine.from_ragged_configs([], DS)
 
 
+def test_from_jobs_gauges_the_padding():
+    """The padding of a small mixed-shape fleet, as ``from_jobs`` counts it:
+    (8, 3), (12, 5) and (6, 2) padded to (12, 5)."""
+    from repro import obs
+    from repro.core import SliceJob
+    obs.reset()
+    f_base = (8000.0, 20000.0, 12000.0, 9000.0, 9000.0)
+    cfgs = [dataclasses.replace(BASE, n_cu=n, n_ec=m, f_base=f_base[:m])
+            for n, m in ((8, 3), (12, 5), (6, 2))]
+    eng = FleetEngine.from_jobs([SliceJob(c, spec=DS) for c in cfgs])
+    assert eng.shape == ShapeConfig(12, 5, BASE.pair_iters)
+    assert obs.gauges() == {
+        "fleet.links_real": 8 * 3 + 12 * 5 + 6 * 2,  # 96
+        "fleet.links_compiled": 3 * 12 * 5,  # 180
+        "fleet.pairs_real": 3 + 10 + 1,
+        "fleet.pairs_compiled": 3 * 10}
+    obs.reset()
+
+
 def test_ragged_pad_shape_and_mask_layout():
     shapes = [ShapeConfig(4, 2, 10), ShapeConfig(6, 3, 10), ShapeConfig(5, 5, 10)]
     assert ragged_pad_shape(shapes) == ShapeConfig(6, 5, 10)
